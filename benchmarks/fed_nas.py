@@ -70,6 +70,7 @@ from repro.data import ClientFleet, VirtualClassification, \
     partition_label
 from repro.engine import ClientSimConfig, FedAvgBaseline, FedEngine, \
     OfflineNas, RealTimeNas, RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import PeakLiveBytes, steady_mean
 
 IMAGE = 16
@@ -846,6 +847,7 @@ def _run_obs_mode(args) -> Dict:
 
 def main():
     import argparse
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         description="execution-backend, payload-codec and "
                     "client-availability comparisons")

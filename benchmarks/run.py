@@ -198,6 +198,8 @@ def bench_roofline():
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--generations", type=int, default=2,
                     help="NAS generations for the federated benches")

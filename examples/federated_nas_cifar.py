@@ -23,9 +23,11 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 from benchmarks import fed_nas
 from repro.core import nsga2
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--generations", type=int, default=5)
     ap.add_argument("--clients", type=int, default=8)

@@ -18,9 +18,11 @@ from repro.core import make_api, nsga2
 from repro.core.choice import random_key
 from repro.data import make_classification, make_clients, partition_iid
 from repro.engine import FedEngine, RealTimeNas, RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     # --- the master model (paper Fig. 3, CPU-reduced) -------------------
     cfg = get_config("cifar-supernet", smoke=True)
     api = make_api(cfg)

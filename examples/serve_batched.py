@@ -12,11 +12,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import greedy_generate
 from repro.models import transformer as tr
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--batch", type=int, default=4)

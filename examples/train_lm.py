@@ -15,11 +15,13 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.data import make_lm_stream
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.train import init_opt, make_train_step
 from repro.models import transformer as tr
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=60)

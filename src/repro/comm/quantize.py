@@ -10,10 +10,10 @@ amortized scale per payload (an O(tensors/params) underestimate, well
 under 0.1% on the supernet masters).
 
 ``backend="pallas"`` routes the elementwise quantize/dequantize through
-the ``repro.kernels.quantize`` Pallas TPU kernel (interpret-mode off-TPU,
-like every kernel in this repo); ``"xla"`` routes through the
-``repro.kernels.ref`` jnp oracles the kernel is swept against — one
-definition of the grid math, so the routes cannot drift
+the ``repro.kernels.quantize`` Pallas TPU kernel (compiled on a TPU,
+interpreted on CPU, like every kernel in this repo); ``"xla"`` routes
+through the ``repro.kernels.ref`` jnp oracles the kernel is swept
+against — one definition of the grid math, so the routes cannot drift
 (``tests/test_kernels.py`` / ``tests/test_comm.py``).
 """
 from __future__ import annotations

@@ -155,25 +155,26 @@ def fill_aggregate_stacked(prev_master: Params,
 
     ``backend="pallas"`` routes the reduction through the
     ``repro.kernels.fill_aggregate`` TPU kernel on the flattened
-    parameter vector (the same route ``fill_aggregate`` takes); off-TPU
-    the kernel body executes in interpret mode (``kernels.ops.INTERPRET``)
-    so the selection is valid everywhere.  Weight normalization is global
-    across chunks, so per-chunk partial sums compose exactly; callers
-    whose chunk weights are ALREADY normalized pass ``total=1.0`` (the
-    fused/mesh routes) — re-deriving it from the float sum would shift
-    every weight by ~1 ulp, and that amplifies over generations of SGD.
+    parameter vector (the same route ``fill_aggregate`` takes); on CPU
+    the kernel body executes in interpret mode
+    (``kernels.ops.interpret_mode``), on a TPU it is compiled.  Weight
+    normalization is global across chunks, so per-chunk partial sums
+    compose exactly; callers whose chunk weights are ALREADY normalized
+    pass ``total=1.0`` (the fused/mesh routes) — re-deriving it from the
+    float sum would shift every weight by ~1 ulp, and that amplifies
+    over generations of SGD.
     """
     if total is None:
         total = float(sum(float(np.sum(w)) for _, _, w in chunks))
     if backend == "pallas":
         return _fill_stacked_pallas(prev_master, chunks, mask_fn, total)
-    acc = None
+    acc = jax.tree.map(lambda x: jnp.zeros(x.shape, jnp.float32),
+                       prev_master)
     for stacked, keys, w in chunks:
         wnorm = jnp.asarray(np.asarray(w, np.float32) / total)
-        part = _fill_stacked_partial(prev_master, stacked,
-                                     jnp.asarray(keys, jnp.int32), wnorm,
-                                     mask_fn=mask_fn)
-        acc = part if acc is None else jax.tree.map(jnp.add, acc, part)
+        acc = _fill_stacked_accumulate(acc, prev_master, stacked,
+                                       jnp.asarray(keys, jnp.int32), wnorm,
+                                       mask_fn=mask_fn)
     return jax.tree.map(lambda a, p: a.astype(p.dtype), acc, prev_master)
 
 
@@ -244,9 +245,14 @@ def fill_partial(prev_master: Params, stacked: Params, masks: Params,
 
 
 @functools.partial(jax.jit, static_argnames=("mask_fn",))
-def _fill_stacked_partial(prev_master, stacked, keys, wnorm, mask_fn):
+def _fill_stacked_accumulate(acc, prev_master, stacked, keys, wnorm,
+                             mask_fn):
+    """``acc`` plus one chunk's Algorithm 3 partial sum, in one program —
+    the expression ``fill_bucket_partial`` adds per group inside the
+    fused fill, so the compiler rounds both the same way."""
     masks = jax.vmap(mask_fn)(stacked, keys)
-    return fill_partial(prev_master, stacked, masks, wnorm)
+    return jax.tree.map(jnp.add, acc,
+                        fill_partial(prev_master, stacked, masks, wnorm))
 
 
 def fedavg(uploads: Sequence[Tuple[Params, float]]) -> Params:

@@ -40,7 +40,7 @@ structure, so the fused sharded path is O(1) dispatches per generation.
 
 Every backend routes Algorithm 3 through ``RunConfig.aggregate_backend``
 identically: ``"xla"`` is the jnp reference, ``"pallas"`` the
-``repro.kernels.fill_aggregate`` TPU kernel (interpret-mode off-TPU).
+``repro.kernels.fill_aggregate`` TPU kernel (interpreted on CPU).
 Unknown values are rejected by ``RunConfig`` at construction time.
 
 Payload codecs never appear in this module: when
@@ -76,8 +76,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.aggregate import fedavg, fill_aggregate, \
-    fill_aggregate_stacked, fill_partial
+from repro.core.aggregate import fedavg, fill_aggregate_stacked, \
+    fill_partial
 from repro.core.federated import client_update_fn, eval_count_fn, \
     weighted_test_error
 from repro.core.supernet import SupernetAPI
@@ -330,7 +330,8 @@ class LoopBackend:
     """Reference execution: one jitted dispatch per (individual, client)
     pair — exactly the pre-engine (per-pair Python loop) semantics that
     the batched backends are tested against.  Algorithm 3 routes through
-    ``fill_aggregate(backend=cfg.aggregate_backend)``."""
+    ``fill_aggregate_stacked(backend=cfg.aggregate_backend)``, one chunk
+    per client group."""
 
     name = "loop"
     # shared no-op unless FedEngine attaches a real Telemetry (repro.obs)
@@ -358,23 +359,37 @@ class LoopBackend:
         return survivors is None or int(cid) in survivors
 
     def train_fill(self, master, keys, groups, lr, survivors=None):
-        uploads = []
+        # Algorithm 3 is reduced per group and the group partials summed
+        # in group order — the batched backends' order (fill_partial), so
+        # the reference and the fused programs agree to the last ulp of
+        # the aggregation and SGD has no reduction-order drift to amplify
+        chunks = []
         for key, group in zip(keys, groups):
             jkey = np.asarray(key, np.int32)
+            ups, ws = [], []
             for cid in group:
-                if not self._alive(survivors, cid):
-                    continue          # dropped: its upload never arrives
                 c = self.clients[int(cid)]
-                xb, yb = c.train
-                p_k = self.update(master, jkey, xb, yb, lr)
-                self.dispatches += 1
-                uploads.append((p_k, self.api.trained_mask(p_k, key),
-                                c.weight))
-        if not uploads:
+                if self._alive(survivors, cid):
+                    xb, yb = c.train
+                    ups.append(self.update(master, jkey, xb, yb, lr))
+                    ws.append(c.weight)
+                    self.dispatches += 1
+                else:
+                    # dropped: its upload never arrives, so its row has
+                    # weight 0 (the master stands in) — the group keeps
+                    # the rows the batched backends reduce
+                    ups.append(master)
+                    ws.append(0.0)
+            if any(ws):
+                chunks.append((jax.tree.map(lambda *xs: jnp.stack(xs), *ups),
+                               np.tile(jkey, (len(ups), 1)),
+                               np.asarray(ws, np.float32)))
+        if not chunks:
             return master
-        self.dispatches += 1
-        return fill_aggregate(master, uploads,
-                              backend=self.cfg.aggregate_backend)
+        self.dispatches += len(chunks)
+        return fill_aggregate_stacked(master, chunks,
+                                      mask_fn=self.api.trained_mask,
+                                      backend=self.cfg.aggregate_backend)
 
     def train_fedavg(self, params, key, client_ids, lr, survivors=None):
         jkey = np.asarray(key, np.int32)
@@ -643,6 +658,26 @@ class StackedClientBase:
                         place(w)))
         return out
 
+    def _pallas_fill(self, master, buckets, outs):
+        """Algorithm 3 through the ``fill_aggregate`` kernel over the
+        stacked uploads ``outs`` (one (Gp, S, ...) tree per bucket of
+        ``buckets``): one kernel call per group that has surviving
+        weight, partials summed bucket by bucket and group by group —
+        the order of ``fill_bucket_partial`` and of the loop reference,
+        so the routes differ only by the kernel's own row sum."""
+        chunks = []
+        for (keys_a, _, _, w), out in zip(buckets, outs):
+            keys_np, w_np = np.asarray(keys_a), np.asarray(w)
+            for g in np.flatnonzero(w_np.any(axis=1)):
+                chunks.append((jax.tree.map(lambda x: x[g], out),
+                               np.tile(keys_np[g], (w_np.shape[1], 1)),
+                               w_np[g]))
+        master = fill_aggregate_stacked(master, chunks,
+                                        mask_fn=self.api.trained_mask,
+                                        backend="pallas", total=1.0)
+        self.dispatches += len(chunks)
+        return master
+
     def train_fedavg(self, params, key, client_ids, lr, survivors=None):
         """Algorithm 1 for one model == the population path at P = 1."""
         return self.train_fedavg_population([params], [key], client_ids,
@@ -823,19 +858,7 @@ class VmapBackend(StackedClientBase):
             # local SGD, then Algorithm 3 through the Pallas kernel
             outs = self._fused_uploads(master, buckets, lr)
             self.dispatches += 1
-            chunks = []
-            for (keys_a, _, _, w), out in zip(buckets, outs):
-                gp, s = np.asarray(w).shape
-                flat = jax.tree.map(
-                    lambda x: x.reshape((gp * s,) + x.shape[2:]), out)
-                chunks.append((flat,
-                               np.repeat(np.asarray(keys_a), s, axis=0),
-                               np.asarray(w).reshape(-1)))
-            master = fill_aggregate_stacked(master, chunks,
-                                            mask_fn=self.api.trained_mask,
-                                            backend="pallas", total=1.0)
-            self.dispatches += len(chunks)
-            return master
+            return self._pallas_fill(master, buckets, outs)
         # donated master: the caller's buffers are reused for the update
         out = self._fused_fill(master, buckets, lr)
         self.dispatches += 1

@@ -77,10 +77,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from repro.core.aggregate import fill_aggregate_stacked
 from repro.core.federated import client_update_fn, eval_count_fn
 from repro.core.supernet import SupernetAPI
 from repro.data.pipeline import ClientDataset
@@ -137,10 +135,10 @@ class MeshBackend(StackedClientBase):
                 fill_bucket_partial(upd, mask_fn, master,
                                     keys, xb, yb, w, lr), axes)
 
-        fill_sm = shard_map(
+        fill_sm = jax.shard_map(
             fill_body, mesh=self.mesh,
             in_specs=(rep, pop, pop, pop, pop, rep),
-            out_specs=rep, check_rep=False)
+            out_specs=rep, check_vma=False)
         # every jitted program is wrapped by repro.obs.traced (recompile
         # counter + named_scope label, exactly as in VmapBackend); the
         # fused wrappers below call the RAW shard_map callables, so each
@@ -152,10 +150,11 @@ class MeshBackend(StackedClientBase):
         def uploads_body(master, keys, xb, yb, lr):
             return train_bucket_uploads(upd, master, keys, xb, yb, lr)
 
-        self._train_uploads = jax.jit(traced("train_uploads", tc, shard_map(
-            uploads_body, mesh=self.mesh,
-            in_specs=(rep, pop, pop, pop, rep),
-            out_specs=pop, check_rep=False)))
+        self._train_uploads = jax.jit(traced(
+            "train_uploads", tc, jax.shard_map(
+                uploads_body, mesh=self.mesh,
+                in_specs=(rep, pop, pop, pop, rep),
+                out_specs=pop, check_vma=False)))
 
         # -- per-individual FedAvg over replicated participants -------------
         def fedavg_body(ps, keys, xb, yb, wn, lr):
@@ -163,10 +162,10 @@ class MeshBackend(StackedClientBase):
             # xb/yb (S, nbat, B, ...) and wn (S,) replicated
             return fedavg_population_bucket(upd, ps, keys, xb, yb, wn, lr)
 
-        fedavg_sm = shard_map(
+        fedavg_sm = jax.shard_map(
             fedavg_body, mesh=self.mesh,
             in_specs=(pop, pop, rep, rep, rep, rep),
-            out_specs=pop, check_rep=False)
+            out_specs=pop, check_vma=False)
         self._fedavg_partial = jax.jit(traced("fedavg_partial", tc,
                                               fedavg_sm))
 
@@ -178,10 +177,10 @@ class MeshBackend(StackedClientBase):
             return eval_bucket_counts(ev, params, keys, xb, yb, alive,
                                       tile=cfg.vmap_eval_tile)
 
-        eval_shared_sm = shard_map(
+        eval_shared_sm = jax.shard_map(
             eval_shared_body, mesh=self.mesh,
             in_specs=(rep, pop, rep, rep, rep),
-            out_specs=pop, check_rep=False)
+            out_specs=pop, check_vma=False)
         self._eval_shared_counts = jax.jit(traced("eval_shared_counts", tc,
                                                   eval_shared_sm))
 
@@ -189,10 +188,10 @@ class MeshBackend(StackedClientBase):
             return eval_paired_bucket_counts(ev, ps, keys, xb, yb, alive,
                                              tile=cfg.vmap_eval_tile)
 
-        eval_paired_sm = shard_map(
+        eval_paired_sm = jax.shard_map(
             eval_paired_body, mesh=self.mesh,
             in_specs=(pop, pop, rep, rep, rep),
-            out_specs=pop, check_rep=False)
+            out_specs=pop, check_vma=False)
         self._eval_paired_counts = jax.jit(traced("eval_paired_counts", tc,
                                                   eval_paired_sm))
 
@@ -248,11 +247,15 @@ class MeshBackend(StackedClientBase):
     def _put_pop_tree(self, tree):
         return jax.tree.map(self._put_pop, tree)
 
+    def _put_rep(self, tree):
+        """Replicate a pytree over the mesh (a no-op for arrays already
+        placed so)."""
+        return jax.device_put(tree, NamedSharding(self.mesh, PartitionSpec()))
+
     def _place_test(self, arr):
         """Replicate the cached test stacks over the mesh once, so the
         eval programs (in_specs=rep) never re-transfer them."""
-        return jax.device_put(jnp.asarray(arr),
-                              NamedSharding(self.mesh, PartitionSpec()))
+        return self._put_rep(jnp.asarray(arr))
 
     # -- train_fill ----------------------------------------------------------
 
@@ -277,6 +280,10 @@ class MeshBackend(StackedClientBase):
                                             survivors=survivors)
         if not buckets:
             return master
+        # the fill programs return a mesh-replicated master; placing the
+        # incoming one the same way gives them one input sharding from
+        # the first generation on, so each traces once
+        master = self._put_rep(master)
         if self.cfg.aggregate_backend == "pallas":
             return self._train_fill_pallas(master, buckets, lr)
         lr = jnp.float32(lr)
@@ -294,24 +301,14 @@ class MeshBackend(StackedClientBase):
         return jax.tree.map(lambda a, p: a.astype(p.dtype), acc, master)
 
     def _train_fill_pallas(self, master, buckets, lr):
-        """Kernel route: run the sharded local SGD, flatten the uploads
-        and hand Algorithm 3 to ``fill_aggregate_stacked(backend="pallas")``
-        (weight-0 padding rows contribute nothing)."""
+        """Kernel route: run the sharded local SGD per bucket and hand the
+        uploads to the shared ``_pallas_fill`` (weight-0 padding groups
+        are skipped, padding rows contribute nothing)."""
         lr = jnp.float32(lr)
-        chunks = []
-        for keys_a, xb, yb, w in buckets:
-            outs = self._train_uploads(master, keys_a, xb, yb, lr)
-            self.dispatches += 1
-            gp, s = w.shape
-            flat = jax.tree.map(
-                lambda x: x.reshape((gp * s,) + x.shape[2:]), outs)
-            chunks.append((flat, np.repeat(np.asarray(keys_a), s, axis=0),
-                           np.asarray(w).reshape(-1)))
-        master = fill_aggregate_stacked(master, chunks,
-                                        mask_fn=self.api.trained_mask,
-                                        backend="pallas", total=1.0)
-        self.dispatches += len(chunks)
-        return master
+        outs = [self._train_uploads(master, keys_a, xb, yb, lr)
+                for keys_a, xb, yb, _ in buckets]
+        self.dispatches += len(buckets)
+        return self._pallas_fill(master, buckets, outs)
 
     # -- FedAvg paths (train_fedavg delegates via StackedClientBase) ---------
 

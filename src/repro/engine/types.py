@@ -168,8 +168,9 @@ class RunConfig:
     Execution:
       * ``aggregate_backend`` — how Algorithm 3 (fill-aggregation) is
         computed: ``"xla"`` (jnp reference) or ``"pallas"`` (the
-        ``repro.kernels.fill_aggregate`` TPU kernel; interpret-mode —
-        i.e. XLA-orchestrated, Python-executed — off-TPU).  Honored by
+        ``repro.kernels.fill_aggregate`` TPU kernel: compiled on a TPU,
+        interpreted on CPU, decided by platform in
+        ``repro.kernels.ops.interpret_mode``).  Honored by
         every execution backend; unknown values raise here, at config
         time.
       * ``backend`` — client-execution backend: ``"loop"`` (reference,

@@ -35,8 +35,8 @@ def _kernel(x_ref, w_ref, o_ref, acc_scr, *, num_d_blocks: int):
         o_ref[...] = acc_scr[...].astype(o_ref.dtype)
 
 
-def expert_gemm(x, w, *, block_c=BLOCK_C, block_f=BLOCK_F, block_d=BLOCK_D,
-                interpret=True):
+def expert_gemm(x, w, *, interpret, block_c=BLOCK_C, block_f=BLOCK_F,
+                block_d=BLOCK_D):
     """x: (E, C, D); w: (E, D, F) -> (E, C, F)."""
     e, c, d = x.shape
     _, _, f = w.shape

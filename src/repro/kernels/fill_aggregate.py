@@ -26,9 +26,13 @@ def _kernel(c_ref, m_ref, w_ref, p_ref, o_ref):
     o_ref[...] = jnp.sum(w[:, None] * filled, axis=0).astype(o_ref.dtype)
 
 
-def fill_aggregate(clients, masks, weights, prev, *, block=DEFAULT_BLOCK,
-                   interpret=True, donate_prev=False):
+def fill_aggregate(clients, masks, weights, prev, *, interpret,
+                   block=DEFAULT_BLOCK, donate_prev=False):
     """clients, masks: (m, P); weights: (m,); prev: (P,) -> (P,).
+
+    ``interpret`` runs the kernel body in Python (CPU) instead of
+    compiling it for the TPU; ``repro.kernels.ops`` decides it by
+    platform.
 
     ``donate_prev`` aliases the ``prev`` buffer into the output
     (``input_output_aliases``): grid step i reads prev's block i before

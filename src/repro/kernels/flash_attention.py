@@ -83,13 +83,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         o_ref[...] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
 
 
-def flash_attention(q, k, v, *, causal=True, window=0,
-                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K,
-                    interpret=True):
+def flash_attention(q, k, v, *, interpret, causal=True, window=0,
+                    block_q=DEFAULT_BLOCK_Q, block_k=DEFAULT_BLOCK_K):
     """q, k, v: (BH, S, D) with identical head counts.  Returns (BH, S, D).
 
-    ``interpret=True`` executes the kernel body in Python on CPU (this
-    container); on real TPUs pass interpret=False.
+    ``interpret=True`` executes the kernel body in Python (CPU);
+    ``False`` compiles it for the TPU.
     """
     bh, s, d = q.shape
     block_q = min(block_q, s)

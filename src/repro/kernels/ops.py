@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``INTERPRET`` defaults to True (this container is CPU-only; interpret mode
-executes kernel bodies in Python for validation).  On real TPUs set
-``repro.kernels.ops.INTERPRET = False`` once at startup.
+Interpret mode is decided by the platform the wrappers trace on
+(``interpret_mode``): kernel bodies run in Python on CPU, where the tests
+validate them against ``repro.kernels.ref``, and compile to Mosaic
+custom calls on a TPU.  Any other platform is an error — there is no
+silent fallback that would run the kernel bodies in Python on a device.
 """
 from __future__ import annotations
 
@@ -18,7 +20,19 @@ from repro.kernels import flash_attention as _flash
 from repro.kernels import quantize as _q
 from repro.kernels import ssd_scan as _ssd
 
-INTERPRET = True
+
+
+def interpret_mode() -> bool:
+    """Whether the Pallas kernels run interpreted: True on CPU, False on
+    TPU; any other platform raises (the kernels are written for the TPU
+    and would otherwise run their bodies in Python there)."""
+    platform = jax.default_backend()
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(f"Pallas kernels run on 'tpu' (compiled) or 'cpu' "
+                       f"(interpreted); the platform is {platform!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window"))
@@ -36,7 +50,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0):
         v = jnp.repeat(v, h // kh, axis=2)
     fold = lambda x: x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
     out = _flash.flash_attention(fold(q), fold(k), fold(v), causal=causal,
-                                 window=window, interpret=INTERPRET)
+                                 window=window, interpret=interpret_mode())
     return out.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
@@ -47,20 +61,20 @@ def ssd_scan(xs, a, bm, cm, initial_state=None):
     x_k = jnp.moveaxis(xs, 3, 1)                 # (B, H, NC, Q, P)
     a_k = jnp.moveaxis(a, 3, 1)                  # (B, H, NC, Q)
     y, s = _ssd.ssd_scan(x_k, a_k, bm, cm, initial_state,
-                         interpret=INTERPRET)
+                         interpret=interpret_mode())
     return jnp.moveaxis(y, 1, 3), s
 
 
 @jax.jit
 def _fill_aggregate_jit(clients, masks, weights, prev):
     return _fa.fill_aggregate(clients, masks, weights, prev,
-                              interpret=INTERPRET)
+                              interpret=interpret_mode())
 
 
 @functools.partial(jax.jit, donate_argnums=(3,))
 def _fill_aggregate_donate_jit(clients, masks, weights, prev):
     return _fa.fill_aggregate(clients, masks, weights, prev,
-                              interpret=INTERPRET, donate_prev=True)
+                              interpret=interpret_mode(), donate_prev=True)
 
 
 def fill_aggregate(clients, masks, weights, prev, donate_prev=False):
@@ -81,19 +95,19 @@ def fill_aggregate(clients, masks, weights, prev, donate_prev=False):
 @jax.jit
 def expert_gemm(x, w):
     """x: (E, C, D); w: (E, D, F) -> (E, C, F)."""
-    return _eg.expert_gemm(x, w, interpret=INTERPRET)
+    return _eg.expert_gemm(x, w, interpret=interpret_mode())
 
 
 @jax.jit
 def quantize_int8(x, scale):
     """x: (P,) float; scale: scalar -> (P,) int8 (symmetric grid)."""
-    return _q.quantize_int8(x, scale, interpret=INTERPRET)
+    return _q.quantize_int8(x, scale, interpret=interpret_mode())
 
 
 @jax.jit
 def dequantize_int8(q, scale):
     """q: (P,) int8; scale: scalar -> (P,) float32 (``q * scale``)."""
-    return _q.dequantize_int8(q, scale, interpret=INTERPRET)
+    return _q.dequantize_int8(q, scale, interpret=interpret_mode())
 
 
 @jax.jit

@@ -53,12 +53,12 @@ def _blocked_1d(kernel, x, scale, out_dtype, block, interpret):
     return out[:p]
 
 
-def quantize_int8(x, scale, *, block=DEFAULT_BLOCK, interpret=True):
+def quantize_int8(x, scale, *, interpret, block=DEFAULT_BLOCK):
     """x: (P,) float; scale: scalar -> (P,) int8 on the symmetric grid."""
     return _blocked_1d(_quant_kernel, x, scale, jnp.int8, block, interpret)
 
 
-def dequantize_int8(q, scale, *, dtype=jnp.float32, block=DEFAULT_BLOCK,
-                    interpret=True):
+def dequantize_int8(q, scale, *, interpret, dtype=jnp.float32,
+                    block=DEFAULT_BLOCK):
     """q: (P,) int8; scale: scalar -> (P,) ``dtype`` (``q * scale``)."""
     return _blocked_1d(_dequant_kernel, q, scale, dtype, block, interpret)

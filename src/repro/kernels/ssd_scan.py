@@ -63,7 +63,7 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, s_ref, state_scr, *,
         s_ref[...] = state_scr[...]
 
 
-def ssd_scan(x, a, b, c, initial_state=None, *, interpret=True):
+def ssd_scan(x, a, b, c, initial_state=None, *, interpret):
     """x: (B, H, NC, Q, P); a: (B, H, NC, Q); b, c: (B, NC, Q, N).
 
     Returns (y: (B, H, NC, Q, P), final_state: (B, H, P, N)).

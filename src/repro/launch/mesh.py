@@ -8,14 +8,22 @@ import, while tests and benches must keep seeing the single real device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the engine's
+    ``shard_map``/``NamedSharding`` code leaves propagation to the
+    compiler, and explicit axes (``make_mesh``'s default) would type the
+    shardings of every intermediate reshape instead."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> Mesh:
@@ -25,7 +33,7 @@ def make_host_mesh() -> Mesh:
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` this yields an
     N-way ``data`` axis on plain CPU hosts (the multi-device CI recipe)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def data_axes(mesh: Mesh):

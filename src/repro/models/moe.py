@@ -237,10 +237,8 @@ def _moe_apply_shard_map(p, x, cfg, mesh, *, ff_mask=None):
         aux = jax.lax.pmean(aux, data_ax + ("model",))
         return y2[: bl * sl].reshape(bl, sl, d), aux
 
-    from jax.experimental.shard_map import shard_map
-
     fsdp = data_ax if len(data_ax) > 1 else data_ax[0]
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(data_ax, None, None), P(None, None),
                   P("model", fsdp, None), P("model", fsdp, None),

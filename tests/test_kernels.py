@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpret=True)."""
+"""Per-kernel shape/dtype sweeps vs the pure-jnp oracles (interpreted on CPU
+by ``ops.interpret_mode``)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
