@@ -1,0 +1,9 @@
+"""device_idle: the share of the traced window in which no operation
+ran on the device, in percent (1 - busy union / window)."""
+
+
+def read(rec):
+    tr = rec["trace"]
+    if not tr or tr["window_s"] <= 0 or tr["busy_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
