@@ -514,7 +514,24 @@ class StackedClientBase:
                     store.append(({key[i]: row
                                    for row, i in enumerate(idxs)}, xb, yb))
                 cache[key] = store
+            self._count_staged(a for _, xb, yb in store for a in (xb, yb))
         return cache[key]
+
+    def _count_staged(self, arrays) -> None:
+        """Book the bytes of freshly staged shard arrays on the round's
+        ``staged_bytes`` counter (computed only with telemetry on)."""
+        if self.telemetry.enabled:
+            self.telemetry.count("staged_bytes",
+                                 float(sum(a.nbytes for a in arrays)))
+
+    def _launch(self, program, *args):
+        """Call one jitted program under the telemetry ``launch`` span —
+        from call to return, which is the enqueue and not the device
+        work — and count the dispatch."""
+        with self.telemetry.span("launch"):
+            out = program(*args)
+        self.dispatches += 1
+        return out
 
     def _client_weight(self, cid, survivors) -> float:
         """A client's aggregation weight this round: 0 for dropped
@@ -572,7 +589,20 @@ class StackedClientBase:
                     dataclasses.replace(cb, xb=self._place_test(cb.xb),
                                         yb=self._place_test(cb.yb))
                     for cb in self._group_batches(key, "test")]
+            self._count_staged(a for cb in cache[key] for a in (cb.xb,
+                                                                 cb.yb))
         return cache[key]
+
+    def _eval_inputs(self, client_ids, survivors):
+        """The evaluation's staged inputs, under the ``stage`` span: the
+        participants' test stacks, per stack the survivor mask, the
+        surviving sample total, and the (xb, yb, alive) shards the
+        fused programs take."""
+        with self.telemetry.span("stage"):
+            batches = self._test_batches(client_ids)
+            masks = self._alive_masks(batches, survivors)
+            shards = tuple((cb.xb, cb.yb, m) for cb, m in zip(batches, masks))
+            return batches, masks, self._alive_total(batches, masks), shards
 
     def _place_test(self, arr):
         """Device placement for the cached test stacks; ``MeshBackend``
@@ -828,8 +858,8 @@ class VmapBackend(StackedClientBase):
             jkey = np.asarray(key, np.int32)
             for xb, yb, w, n in self._group_train_gather(group, survivors,
                                                          store=store):
-                out = self._scan_update(master, jkey, xb, yb, lr)
-                self.dispatches += 1
+                out = self._launch(self._scan_update, master, jkey, xb, yb,
+                                   lr)
                 chunks.append((out, np.tile(jkey, (n, 1)), w))
         if not chunks or not any(np.any(w) for _, _, w in chunks):
             return master              # nobody survived: master untouched
@@ -848,48 +878,47 @@ class VmapBackend(StackedClientBase):
                                      survivors)
         if total == 0.0:
             return master
-        buckets = tuple(self._group_bucket_arrays(keys, groups, total,
-                                                  survivors=survivors))
+        with self.telemetry.span("stage"):
+            buckets = tuple(self._group_bucket_arrays(keys, groups, total,
+                                                      survivors=survivors))
+            lr = jnp.float32(lr)
         if not buckets:
             return master
-        lr = jnp.float32(lr)
         if self.cfg.aggregate_backend == "pallas":
             # partial fusion: one program for the whole population's
             # local SGD, then Algorithm 3 through the Pallas kernel
-            outs = self._fused_uploads(master, buckets, lr)
-            self.dispatches += 1
+            outs = self._launch(self._fused_uploads, master, buckets, lr)
             return self._pallas_fill(master, buckets, outs)
         # donated master: the caller's buffers are reused for the update
-        out = self._fused_fill(master, buckets, lr)
-        self.dispatches += 1
-        return out
+        return self._launch(self._fused_fill, master, buckets, lr)
 
     def _fedavg_from_batches(self, params, jkey, batches, total, lr):
         acc = None
         for xb, yb, w, _ in batches:
-            part = self._scan_update_avg(params, jkey, xb, yb,
-                                         lr, w / total)
-            self.dispatches += 1
+            part = self._launch(self._scan_update_avg, params, jkey, xb, yb,
+                                lr, w / total)
             acc = part if acc is None else jax.tree.map(jnp.add, acc, part)
         return jax.tree.map(lambda a, p: a.astype(p.dtype), acc, params)
 
     def train_fedavg_population(self, params_list, keys, client_ids, lr,
                                 survivors=None):
         # gather the participants' train shards once for every individual
-        batches = list(self._group_train_gather(client_ids, survivors))
+        with self.telemetry.span("stage"):
+            batches = list(self._group_train_gather(client_ids, survivors))
         total = self._survivor_total(client_ids, survivors)
         if total == 0.0:               # nobody survived: models untouched
             return list(params_list)
         if self.cfg.fused:
             if not params_list:
                 return []
-            ps = jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
-            karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
-                                         for k in keys]))
-            buckets = tuple((xb, yb, jnp.asarray(w / total))
-                            for xb, yb, w, _ in batches)
-            out = self._fused_fedavg(ps, karr, buckets, jnp.float32(lr))
-            self.dispatches += 1
+            with self.telemetry.span("stage"):
+                ps = jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
+                karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
+                                             for k in keys]))
+                buckets = tuple((xb, yb, jnp.asarray(w / total))
+                                for xb, yb, w, _ in batches)
+                lr = jnp.float32(lr)
+            out = self._launch(self._fused_fedavg, ps, karr, buckets, lr)
             return [jax.tree.map(lambda x: x[i], out)
                     for i in range(len(params_list))]
         return [self._fedavg_from_batches(p, np.asarray(k, np.int32),
@@ -906,49 +935,42 @@ class VmapBackend(StackedClientBase):
             full = (m // tile) * tile
             tail = batch.xb.shape[1:]
             if full:
-                wrong += int(self._eval_tiles(
-                    params, jkey,
+                wrong += int(self._launch(
+                    self._eval_tiles, params, jkey,
                     batch.xb[:full].reshape((full // tile, tile) + tail),
                     batch.yb[:full].reshape((full // tile, tile)
                                             + batch.yb.shape[1:]),
                     alive[:full].reshape((full // tile, tile))))
-                self.dispatches += 1
             if m > full:
-                wrong += int(self._eval_tiles(params, jkey,
-                                              batch.xb[None, full:],
-                                              batch.yb[None, full:],
-                                              alive[None, full:]))
-                self.dispatches += 1
+                wrong += int(self._launch(self._eval_tiles, params, jkey,
+                                          batch.xb[None, full:],
+                                          batch.yb[None, full:],
+                                          alive[None, full:]))
         return wrong / total
 
     def eval_shared(self, params, keys, client_ids, survivors=None):
-        batches = self._test_batches(client_ids)
-        masks = self._alive_masks(batches, survivors)
-        total = self._alive_total(batches, masks)
+        batches, masks, total, shards = self._eval_inputs(client_ids,
+                                                          survivors)
         if self.cfg.fused:
-            karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
-                                         for k in keys]))
-            counts = self._fused_eval_shared(
-                params, karr, tuple((cb.xb, cb.yb, m)
-                                    for cb, m in zip(batches, masks)))
-            self.dispatches += 1
+            with self.telemetry.span("stage"):
+                karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
+                                             for k in keys]))
+            counts = self._launch(self._fused_eval_shared, params, karr,
+                                  shards)
             return self._rates(counts, total, len(keys))
         return np.asarray([self._eval_one(params, np.asarray(k, np.int32),
                                           batches, masks, total)
                            for k in keys])
 
     def eval_paired(self, params_list, keys, client_ids, survivors=None):
-        batches = self._test_batches(client_ids)
-        masks = self._alive_masks(batches, survivors)
-        total = self._alive_total(batches, masks)
+        batches, masks, total, shards = self._eval_inputs(client_ids,
+                                                          survivors)
         if self.cfg.fused:
-            ps = jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
-            karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
-                                         for k in keys]))
-            counts = self._fused_eval_paired(
-                ps, karr, tuple((cb.xb, cb.yb, m)
-                                for cb, m in zip(batches, masks)))
-            self.dispatches += 1
+            with self.telemetry.span("stage"):
+                ps = jax.tree.map(lambda *xs: jnp.stack(xs), *params_list)
+                karr = jnp.asarray(np.stack([np.asarray(k, np.int32)
+                                             for k in keys]))
+            counts = self._launch(self._fused_eval_paired, ps, karr, shards)
             return self._rates(counts, total, len(keys))
         return np.asarray([self._eval_one(p, np.asarray(k, np.int32),
                                           batches, masks, total)

@@ -121,42 +121,26 @@ class FedEngine:
         if reset is not None:        # CodecBackend: drop EF residuals
             reset()
         self.sim = ClientSimulator(cfg.client_sim, len(self.clients))
-        self.strategy.setup(self)
         tel = self.telemetry
         tel.start_run(self)
-        with tel.run_capture():   # jax.profiler.trace when configured
+        # compile listeners and the profile_gens capture, when configured
+        with tel.run_scope():
+            with tel.span("init"):           # booked into generation 1
+                self.strategy.setup(self)
             # perf_counter, not time.time(): wall-clock is not monotonic,
             # an NTP step mid-run would corrupt the recorded round_s
             t0 = t_prev = time.perf_counter()
             for gen in range(1, cfg.generations + 1):
-                lr = float(round_decay(cfg.lr0, cfg.lr_decay, gen - 1))
-                with tel.span("sample"):
-                    sampled = sample_participants(self.rng,
-                                                  len(self.clients),
-                                                  cfg.participation)
-                # availability / dropout draw (sim RNG only — the search
-                # RNG stream above is untouched by the simulation)
-                with tel.span("availability"):
-                    ctx = self.sim.draw_round(sampled)
-                self.round_ctx = ctx
-                report = self.strategy.round(self, gen, ctx.participants,
-                                             lr)
-                report.down_gb = self.stats.down_bytes / 1e9
-                report.up_gb = self.stats.up_bytes / 1e9
-                report.train_passes = self.stats.client_train_passes
-                if ctx.active:
-                    report.n_sampled = ctx.n_sampled
-                    report.n_available = len(ctx.participants)
-                    report.n_dropped = ctx.n_dropped
-                    report.n_survivors = ctx.n_survivors
-                    report.wasted_down_gb = \
-                        self.stats.wasted_down_bytes / 1e9
-                now = time.perf_counter()
-                report.wall_s = now - t0      # cumulative since run()
-                report.round_s = now - t_prev  # this round's delta
-                t_prev = now
-                self.reports.append(report)
-                tel.end_round(gen, report.round_s, self)
+                # the step marker runs from the first sample through
+                # end_round; the user callback stays outside it
+                with tel.generation(gen):
+                    report = self._round(gen)
+                    now = time.perf_counter()
+                    report.wall_s = now - t0      # cumulative since run()
+                    report.round_s = now - t_prev  # this round's delta
+                    t_prev = now
+                    self.reports.append(report)
+                    tel.end_round(gen, report.round_s, self)
                 if callback:
                     callback(gen, report)
         # a stale RoundSim must not leak into strategies driven manually
@@ -165,3 +149,28 @@ class FedEngine:
         return EngineResult(reports=self.reports, stats=self.stats,
                             extras=self.strategy.extras(self),
                             telemetry=tel.result(self))
+
+    def _round(self, gen: int) -> RoundReport:
+        """One generation: sampling, the availability draw, the
+        strategy's round and the report's traffic columns."""
+        cfg, tel = self.cfg, self.telemetry
+        lr = float(round_decay(cfg.lr0, cfg.lr_decay, gen - 1))
+        with tel.span("sample"):
+            sampled = sample_participants(self.rng, len(self.clients),
+                                          cfg.participation)
+        # availability / dropout draw (sim RNG only — the search RNG
+        # stream above is untouched by the simulation)
+        with tel.span("availability"):
+            ctx = self.sim.draw_round(sampled)
+        self.round_ctx = ctx
+        report = self.strategy.round(self, gen, ctx.participants, lr)
+        report.down_gb = self.stats.down_bytes / 1e9
+        report.up_gb = self.stats.up_bytes / 1e9
+        report.train_passes = self.stats.client_train_passes
+        if ctx.active:
+            report.n_sampled = ctx.n_sampled
+            report.n_available = len(ctx.participants)
+            report.n_dropped = ctx.n_dropped
+            report.n_survivors = ctx.n_survivors
+            report.wasted_down_gb = self.stats.wasted_down_bytes / 1e9
+        return report

@@ -276,27 +276,28 @@ class MeshBackend(StackedClientBase):
                                      survivors)
         if total == 0.0:
             return master
-        buckets = self._group_bucket_arrays(keys, groups, total,
-                                            survivors=survivors)
+        with self.telemetry.span("stage"):
+            buckets = self._group_bucket_arrays(keys, groups, total,
+                                                survivors=survivors)
+            if buckets:
+                # the fill programs return a mesh-replicated master;
+                # placing the incoming one the same way gives them one
+                # input sharding from the first generation on, so each
+                # traces once
+                master = self._put_rep(master)
+            lr = jnp.float32(lr)
         if not buckets:
             return master
-        # the fill programs return a mesh-replicated master; placing the
-        # incoming one the same way gives them one input sharding from
-        # the first generation on, so each traces once
-        master = self._put_rep(master)
         if self.cfg.aggregate_backend == "pallas":
             return self._train_fill_pallas(master, buckets, lr)
-        lr = jnp.float32(lr)
         if self.cfg.fused:
             # one dispatch for the whole generation's fill-train (the
             # bucket loop runs inside the program; donated master)
-            out = self._fused_fill(master, tuple(buckets), lr)
-            self.dispatches += 1
-            return out
+            return self._launch(self._fused_fill, master, tuple(buckets), lr)
         acc = None
         for keys_a, xb, yb, w in buckets:
-            part = self._fill_partial(master, keys_a, xb, yb, w, lr)
-            self.dispatches += 1
+            part = self._launch(self._fill_partial, master, keys_a, xb, yb,
+                                w, lr)
             acc = part if acc is None else jax.tree.map(jnp.add, acc, part)
         return jax.tree.map(lambda a, p: a.astype(p.dtype), acc, master)
 
@@ -304,10 +305,8 @@ class MeshBackend(StackedClientBase):
         """Kernel route: run the sharded local SGD per bucket and hand the
         uploads to the shared ``_pallas_fill`` (weight-0 padding groups
         are skipped, padding rows contribute nothing)."""
-        lr = jnp.float32(lr)
-        outs = [self._train_uploads(master, keys_a, xb, yb, lr)
+        outs = [self._launch(self._train_uploads, master, keys_a, xb, yb, lr)
                 for keys_a, xb, yb, _ in buckets]
-        self.dispatches += len(buckets)
         return self._pallas_fill(master, buckets, outs)
 
     # -- FedAvg paths (train_fedavg delegates via StackedClientBase) ---------
@@ -321,25 +320,25 @@ class MeshBackend(StackedClientBase):
             return list(params_list)
         n = len(params_list)
         pad = self._pad(n)
-        plist = list(params_list) + [params_list[-1]] * pad
-        klist = [np.asarray(k, np.int32) for k in keys]
-        klist = klist + [klist[-1]] * pad
-        stacked = self._put_pop_tree(
-            jax.tree.map(lambda *xs: jnp.stack(xs), *plist))
-        keys_arr = self._put_pop(np.stack(klist))
-        lr = jnp.float32(lr)
-        if self.cfg.fused:
+        with self.telemetry.span("stage"):
+            plist = list(params_list) + [params_list[-1]] * pad
+            klist = [np.asarray(k, np.int32) for k in keys]
+            klist = klist + [klist[-1]] * pad
+            stacked = self._put_pop_tree(
+                jax.tree.map(lambda *xs: jnp.stack(xs), *plist))
+            keys_arr = self._put_pop(np.stack(klist))
+            lr = jnp.float32(lr)
             buckets = tuple((xb, yb, jnp.asarray(w / total))
                             for xb, yb, w, _ in
                             self._group_train_gather(client_ids, survivors))
-            out = self._fused_fedavg(stacked, keys_arr, buckets, lr)
-            self.dispatches += 1
+        if self.cfg.fused:
+            out = self._launch(self._fused_fedavg, stacked, keys_arr,
+                               buckets, lr)
             return [jax.tree.map(lambda x: x[i], out) for i in range(n)]
         acc = None
-        for xb, yb, w, _ in self._group_train_gather(client_ids, survivors):
-            part = self._fedavg_partial(stacked, keys_arr, xb, yb,
-                                        jnp.asarray(w / total), lr)
-            self.dispatches += 1
+        for xb, yb, wn in buckets:
+            part = self._launch(self._fedavg_partial, stacked, keys_arr, xb,
+                                yb, wn, lr)
             acc = part if acc is None else jax.tree.map(jnp.add, acc, part)
         out = jax.tree.map(lambda a, s: a.astype(s.dtype), acc, stacked)
         return [jax.tree.map(lambda x: x[i], out) for i in range(n)]
@@ -351,55 +350,45 @@ class MeshBackend(StackedClientBase):
         klist = klist + [klist[-1]] * self._pad(len(klist))
         return self._put_pop(np.stack(klist))
 
+    def _eval_counts(self, fused_program, bucket_program, params, karr,
+                     shards, total, n_keys):
+        """Error rates from ``fused_program`` over every test stack
+        (``cfg.fused``), or from one ``bucket_program`` dispatch per
+        stack summed on the host."""
+        if self.cfg.fused:
+            counts = self._launch(fused_program, params, karr, shards)
+            return self._rates(counts, total, n_keys)
+        wrong = np.zeros(karr.shape[0], np.int64)
+        for xb, yb, m in shards:
+            counts = self._launch(bucket_program, params, karr,
+                                  jnp.asarray(xb), jnp.asarray(yb),
+                                  jnp.asarray(m))
+            wrong += np.asarray(counts, np.int64)
+        return wrong[:n_keys] / total
+
     def eval_shared(self, params, keys, client_ids, survivors=None):
-        batches = self._test_batches(client_ids)
-        masks = self._alive_masks(batches, survivors)
-        total = self._alive_total(batches, masks)
+        _, _, total, shards = self._eval_inputs(client_ids, survivors)
         if total == 0:                 # nobody evaluated: pessimistic
             return np.ones(len(keys))
-        karr = self._padded_keys(keys)
-        if self.cfg.fused:
-            counts = self._fused_eval_shared(
-                params, karr, tuple((cb.xb, cb.yb, m)
-                                    for cb, m in zip(batches, masks)))
-            self.dispatches += 1
-            return self._rates(counts, total, len(keys))
-        wrong = np.zeros(karr.shape[0], np.int64)
-        for cb, m in zip(batches, masks):
-            counts = self._eval_shared_counts(params, karr,
-                                              jnp.asarray(cb.xb),
-                                              jnp.asarray(cb.yb),
-                                              jnp.asarray(m))
-            self.dispatches += 1
-            wrong += np.asarray(counts, np.int64)
-        return wrong[:len(keys)] / total
+        with self.telemetry.span("stage"):
+            karr = self._padded_keys(keys)
+        return self._eval_counts(self._fused_eval_shared,
+                                 self._eval_shared_counts, params, karr,
+                                 shards, total, len(keys))
 
     def eval_paired(self, params_list, keys, client_ids, survivors=None):
-        batches = self._test_batches(client_ids)
-        masks = self._alive_masks(batches, survivors)
-        total = self._alive_total(batches, masks)
+        _, _, total, shards = self._eval_inputs(client_ids, survivors)
         if total == 0:                 # nobody evaluated: pessimistic
             return np.ones(len(keys))
-        pad = self._pad(len(params_list))
-        plist = list(params_list) + [params_list[-1]] * pad
-        stacked = self._put_pop_tree(
-            jax.tree.map(lambda *xs: jnp.stack(xs), *plist))
-        karr = self._padded_keys(keys)
-        if self.cfg.fused:
-            counts = self._fused_eval_paired(
-                stacked, karr, tuple((cb.xb, cb.yb, m)
-                                     for cb, m in zip(batches, masks)))
-            self.dispatches += 1
-            return self._rates(counts, total, len(keys))
-        wrong = np.zeros(karr.shape[0], np.int64)
-        for cb, m in zip(batches, masks):
-            counts = self._eval_paired_counts(stacked, karr,
-                                              jnp.asarray(cb.xb),
-                                              jnp.asarray(cb.yb),
-                                              jnp.asarray(m))
-            self.dispatches += 1
-            wrong += np.asarray(counts, np.int64)
-        return wrong[:len(keys)] / total
+        with self.telemetry.span("stage"):
+            pad = self._pad(len(params_list))
+            plist = list(params_list) + [params_list[-1]] * pad
+            stacked = self._put_pop_tree(
+                jax.tree.map(lambda *xs: jnp.stack(xs), *plist))
+            karr = self._padded_keys(keys)
+        return self._eval_counts(self._fused_eval_paired,
+                                 self._eval_paired_counts, stacked, karr,
+                                 shards, total, len(keys))
 
 
 from repro.engine import backends as _backends  # noqa: E402

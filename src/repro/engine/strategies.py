@@ -88,17 +88,19 @@ def _account_train(engine, keys, groups, download_models: bool,
     stats, api = engine.stats, engine.api
     down, up = engine.downlink_codec, engine.uplink_codec
     dropped = {int(c) for c in ctx.dropped}
-    for key, group in zip(keys, groups):
-        payload = api.payload_params(key)
-        for cid in group:
-            dead = int(cid) in dropped
-            if download_models:
-                stats.add_download(payload,      # theta^q + key (t == 1)
-                                   wire_bytes=down.wire_bytes(payload),
-                                   wasted_copies=int(dead))
-            stats.client_train_passes += 1
-            if not dead:
-                stats.add_upload(payload, wire_bytes=up.wire_bytes(payload))
+    with _telemetry(engine).span("accounting"):
+        for key, group in zip(keys, groups):
+            payload = api.payload_params(key)
+            for cid in group:
+                dead = int(cid) in dropped
+                if download_models:
+                    stats.add_download(payload,   # theta^q + key (t == 1)
+                                       wire_bytes=down.wire_bytes(payload),
+                                       wasted_copies=int(dead))
+                stats.client_train_passes += 1
+                if not dead:
+                    stats.add_upload(payload,
+                                     wire_bytes=up.wire_bytes(payload))
 
 
 def _account_eval(engine, n_keys: int, ctx: RoundSim,
@@ -118,16 +120,17 @@ def _account_eval(engine, n_keys: int, ctx: RoundSim,
     stats, api = engine.stats, engine.api
     n_participants = len(ctx.participants)
     n_wasted = ctx.n_dropped
-    for p in model_params:
-        stats.add_eval_download_bytes(
-            BYTES_PER_PARAM * p, copies=n_participants,
-            wire_nbytes=engine.downlink_codec.wire_bytes(p),
-            wasted_copies=n_wasted)
-    stats.add_eval_download_bytes(api.key_bytes * n_keys,
-                                  copies=n_participants,
-                                  wasted_copies=n_wasted)
-    stats.add_eval_upload_bytes(ERROR_COUNT_BYTES * n_keys,
-                                copies=ctx.n_survivors)
+    with _telemetry(engine).span("accounting"):
+        for p in model_params:
+            stats.add_eval_download_bytes(
+                BYTES_PER_PARAM * p, copies=n_participants,
+                wire_nbytes=engine.downlink_codec.wire_bytes(p),
+                wasted_copies=n_wasted)
+        stats.add_eval_download_bytes(api.key_bytes * n_keys,
+                                      copies=n_participants,
+                                      wasted_copies=n_wasted)
+        stats.add_eval_upload_bytes(ERROR_COUNT_BYTES * n_keys,
+                                    copies=ctx.n_survivors)
 
 
 class RealTimeNas:
@@ -191,7 +194,8 @@ class RealTimeNas:
             # nobody reported: no fitness signal this round — selection
             # falls back to the FLOPs objective (pessimistic error 1.0)
             errs = np.ones(len(combined))
-        fl = np.array([api.flops(k) for k in combined], dtype=float)
+        with tel.span("objectives"):
+            fl = np.array([api.flops(k) for k in combined], dtype=float)
         objs = np.stack([errs, fl], axis=1)
 
         # --- NSGA-II environmental selection (l.50-53)
@@ -244,14 +248,16 @@ class OfflineNas:
             # REINITIALIZED from scratch — the paper's central criticism
             inits.append(api.init(jax.random.PRNGKey(self._reinit_seed)))
         down, up = engine.downlink_codec, engine.uplink_codec
+        tel = _telemetry(engine)
         payloads = [api.payload_params(k) for k in keys]
-        for payload in payloads:                 # every client trains
-            stats.add_download(payload, copies=m,
-                               wire_bytes=down.wire_bytes(payload),
-                               wasted_copies=n_dropped)
-            stats.add_upload(payload, copies=ctx.n_survivors,
-                             wire_bytes=up.wire_bytes(payload))
-            stats.client_train_passes += m
+        with tel.span("accounting"):
+            for payload in payloads:             # every client trains
+                stats.add_download(payload, copies=m,
+                                   wire_bytes=down.wire_bytes(payload),
+                                   wasted_copies=n_dropped)
+                stats.add_upload(payload, copies=ctx.n_survivors,
+                                 wire_bytes=up.wire_bytes(payload))
+                stats.client_train_passes += m
         if ctx.n_survivors:
             models = backend.train_fedavg_population(
                 inits, keys, participants, lr, survivors=ctx.survivors)
@@ -266,7 +272,8 @@ class OfflineNas:
                                        survivors=ctx.survivors)
         else:
             errs = np.ones(len(keys))
-        fl = [api.flops(k) for k in keys]
+        with tel.span("objectives"):
+            fl = [api.flops(k) for k in keys]
         return np.stack([errs, np.asarray(fl, dtype=float)], axis=1)
 
     def round(self, engine, gen, participants, lr):
@@ -314,14 +321,15 @@ class FedAvgBaseline:
         ctx = _round_ctx(engine, participants)
         m = len(participants)
         payload = api.payload_params(self.key)
-        stats.add_download(
-            payload, copies=m,
-            wire_bytes=engine.downlink_codec.wire_bytes(payload),
-            wasted_copies=ctx.n_dropped)
-        stats.add_upload(
-            payload, copies=ctx.n_survivors,
-            wire_bytes=engine.uplink_codec.wire_bytes(payload))
-        stats.client_train_passes += m
+        with _telemetry(engine).span("accounting"):
+            stats.add_download(
+                payload, copies=m,
+                wire_bytes=engine.downlink_codec.wire_bytes(payload),
+                wasted_copies=ctx.n_dropped)
+            stats.add_upload(
+                payload, copies=ctx.n_survivors,
+                wire_bytes=engine.uplink_codec.wire_bytes(payload))
+            stats.client_train_passes += m
         if ctx.n_survivors:
             self.params = backend.train_fedavg(self.params, self.key,
                                                participants, lr,

@@ -166,8 +166,127 @@ def test_round_event_times_match_reports(full_run):
         assert e.round_s == r.round_s
         assert e.round_s >= 0.0
         # top-level phases are disjoint intervals inside the round
-        top = sum(s for p, s in e.spans.items() if "/" not in p)
+        # (``init``, the run's set-up, is booked into round 1 but runs
+        # before it)
+        top = sum(s for p, s in e.spans.items()
+                  if "/" not in p and p != "init")
         assert top <= e.round_s + 1e-3
+
+
+def test_round_event_new_spans_and_compiles(full_run):
+    """Leaf spans for staging, launch, accounting and the objectives;
+    ``init`` and the programs' compiles in generation 1 only (a long
+    session may re-trace a small eager helper later, so steady rounds
+    are held to no compile under ``launch``)."""
+    _, res = full_run
+    first, *steady = res.telemetry.events
+    for ev in (first, *steady):
+        paths = set(ev.spans)
+        assert {"accounting", "objectives", "fill_train/stage",
+                "fill_train/launch", "eval/stage", "eval/launch"} <= paths
+    assert "fill_train/stage/download" in first.spans
+    assert "init" in first.spans
+    assert "fill_train/launch/compile" in first.spans
+    assert "eval/launch/compile" in first.spans
+    for ev in steady:
+        assert "init" not in ev.spans
+        assert not [p for p in ev.spans if p.endswith("launch/compile")], \
+            ev.spans
+        assert ev.recompiles == {}
+
+
+@pytest.mark.parametrize("bk", ["vmap", "mesh"])
+def test_launch_spans_count_fused_dispatches(onoff, bk):
+    eng, res = onoff[(bk, True)]["on"]
+    launches = sum(n for e in res.telemetry.events
+                   for p, n in e.span_counts.items()
+                   if p.split("/")[-1] == "launch")
+    assert launches == eng.backend.dispatches == 2 * GENS + 1
+
+
+def test_staged_bytes_counts_what_reaches_the_device(api):
+    from repro.engine.backends import VmapBackend
+    clients = tiny_clients(4, 120)
+    be = VmapBackend(api, clients, RunConfig(population=2, backend="vmap"))
+    tel = Telemetry(TelemetryConfig(gauges=False))
+    be.telemetry = tel
+
+    class Eng:
+        backend = be
+        stats = object()
+
+    tel.start_run(Eng)
+    store = be._train_store([0, 2])
+    tests = be._test_batches([1, 3])
+    be._train_store([2, 0])              # cache hits stage nothing
+    want = (sum(x.nbytes + y.nbytes for _, x, y in store)
+            + sum(cb.xb.nbytes + cb.yb.nbytes for cb in tests))
+    assert want > 0
+    assert tel.end_round(1, 0.0, Eng).counters == {"staged_bytes": want}
+    be._test_batches([1, 3])
+    assert tel.end_round(2, 0.0, Eng).counters == {}
+    off = VmapBackend(api, clients, RunConfig(population=2, backend="vmap"))
+    off._train_store([0, 2])             # NULL_TELEMETRY: nothing to book
+
+
+def test_compile_spans_book_the_union_of_nested_stages():
+    tel = Telemetry(TelemetryConfig(gauges=False))
+
+    class Eng:
+        class backend:
+            trace_counts = {}
+        stats = object()
+
+    trace, lower, build = ("/jax/core/compile/" + e for e in (
+        "jaxpr_trace_duration", "jaxpr_to_mlir_module_duration",
+        "backend_compile_duration"))
+    tel.start_run(Eng)
+    with tel.span("fill_train"):
+        with tel.span("launch"):
+            tel._on_compile(trace, 10.0, 10.5)      # a helper's trace ...
+            tel._on_compile(trace, 10.25, 11.5)     # ... overlapping one
+            tel._on_compile(trace, 9.0, 12.0)       # ... inside the program's
+            tel._on_compile(lower, 12.0, 13.0)
+            tel._on_cache_load(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.75)
+            tel._on_compile(build, 13.0, 14.0)
+            tel._on_compile("/jax/other_duration", 0.0, 100.0)
+    ev = tel.end_round(1, 0.0, Eng)
+    assert ev.spans["fill_train/launch/compile"] == pytest.approx(5.0)
+    assert ev.spans["fill_train/launch/compile/cache_load"] == 0.75
+
+
+def test_compile_listeners_live_only_in_telemetry_runs(api):
+    from jax._src import monitoring
+
+    def listeners():
+        return (len(monitoring.get_event_time_span_listeners()),
+                len(monitoring.get_event_duration_listeners()))
+
+    before = listeners()
+    seen = {}
+    clients = tiny_clients(4, 120)
+    for tel in (None, True):
+        eng = FedEngine(api, clients, RunConfig(
+            population=2, generations=1, seed=0, backend="loop",
+            telemetry=tel))
+        eng.run(callback=lambda gen, rep, t=tel: seen.setdefault(
+            t, listeners()))
+        assert listeners() == before
+    assert seen[None] == before
+    assert seen[True] == (before[0] + 1, before[1] + 1)
+
+
+def test_profile_gens_validation():
+    assert TelemetryConfig(profiler_dir="d",
+                           profile_gens=[2, 1]).profile_gens == (2, 1)
+    with pytest.raises(ValueError, match="go together"):
+        TelemetryConfig(profiler_dir="d")
+    with pytest.raises(ValueError, match="go together"):
+        TelemetryConfig(profile_gens=(1, 1))
+    for bad in ((0, 1), (1, 0)):
+        with pytest.raises(ValueError, match="profile_gens"):
+            TelemetryConfig(profiler_dir="d", profile_gens=bad)
 
 
 def test_fleet_gauges(api):
@@ -219,7 +338,7 @@ def test_injected_retrace_surfaces_in_round_events():
             self.stats = object()       # comm deltas read 0.0 defaults
 
     eng = FakeEngine()
-    tel = Telemetry(TelemetryConfig(gauges=False, annotations=False))
+    tel = Telemetry(TelemetryConfig(gauges=False))
     f = jax.jit(traced("prog", eng.backend.trace_counts, lambda x: x + 1))
     tel.start_run(eng)
     f(jnp.ones(3))
@@ -242,7 +361,7 @@ def test_jsonl_sink_one_line_per_round(api, tmp_path):
     assert [e["gen"] for e in events] == list(range(1, GENS + 1))
     for e in events:
         assert set(e) == {"gen", "round_s", "spans", "span_counts",
-                          "recompiles", "gauges", "comm"}
+                          "recompiles", "gauges", "comm", "counters"}
     # the file mirrors the in-memory ring, event for event
     assert events[-1] == event_dict(res.telemetry.events[-1])
 
@@ -317,6 +436,8 @@ def test_null_telemetry_noop():
         pass
     NULL_TELEMETRY.start_run(None)
     NULL_TELEMETRY.end_round(1, 0.0, None)
-    with NULL_TELEMETRY.run_capture():
-        pass
+    with NULL_TELEMETRY.run_scope():
+        with NULL_TELEMETRY.generation(1):
+            pass
+    NULL_TELEMETRY.count("staged_bytes", 1.0)
     assert NULL_TELEMETRY.result(None) is None
