@@ -6,6 +6,9 @@ depthwise-separable, in 'normal' (C->C) or 'reduction' (C->2C, spatial /2)
 form depending on position.  Only normal blocks carry shortcut connections
 (paper Fig. 4).  BatchNorm affine parameters and moving statistics are
 DISABLED per Section IV.C — normalization uses current-batch statistics only.
+Depthwise 3x3 convolutions (``_depthwise``) are one grouped
+``convolution`` on TPU and nine shifted multiply-adds on CPU, where each
+is the faster.
 
 Branch selection is a traced int32 per block (``lax.switch``), so one
 compilation serves every choice key — unlike the paper's per-key PyTorch
@@ -41,13 +44,37 @@ def conv(x, w, stride=1, groups=1):
 
 
 def _depthwise(x, w, stride=1):
-    """Depthwise KxK conv as K^2 shifted elementwise multiply-adds.
+    """Depthwise KxK conv, w: (K, K, 1, C) (HWIO depthwise layout).
 
-    XLA:CPU lowers grouped convolutions (and especially their transpose in
-    the backward pass) to a per-group loop that is ~100x slower than this
-    formulation; on TPU both lower to the same fused elementwise HLO.
-    w: (K, K, 1, C) (HWIO depthwise layout).
+    Output pixel ``i`` sees inputs ``stride*i - K//2 .. stride*i + K//2``
+    (zero outside) in both formulations; the platform picks one, no
+    setting does.  XLA:TPU runs one grouped convolution faster than K^2
+    shifted multiply-adds (each tap re-reads the activation from HBM);
+    XLA:CPU runs the grouped convolution 5-25x slower than the taps at
+    the shapes CPU runs use (measurements in PERF.md).  The choice is
+    made while tracing, from the process's platform, as
+    ``repro.kernels.ops.interpret_mode`` makes its own.
     """
+    if jax.default_backend() == "cpu":
+        return _depthwise_taps(x, w, stride)
+    return _depthwise_grouped(x, w, stride)
+
+
+def _depthwise_grouped(x, w, stride=1):
+    """One grouped convolution, one group a channel.  Padding is explicit,
+    ``K // 2`` on each side: at stride 2 on an even input ``"SAME"`` would
+    pad (0, 1) and shift the window by one.  The stride lives inside the
+    convolution, so strided blocks compute only the pixels they keep."""
+    p = w.shape[0] // 2
+    return jax.lax.conv_general_dilated(
+        x, w, (stride, stride), ((p, p), (p, p)),
+        dimension_numbers=("NHWC", "HWIO", "NHWC"),
+        feature_group_count=x.shape[-1])
+
+
+def _depthwise_taps(x, w, stride=1):
+    """K^2 shifted elementwise multiply-adds over the padded input, then
+    the stride."""
     k = w.shape[0]
     ph = pw = k // 2
     xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))

@@ -6,6 +6,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.models import cnn
 from repro.models import transformer as tr
 from repro.models.layers import apply_rope, cross_entropy, fused_cross_entropy
 
@@ -198,3 +199,64 @@ def test_fused_ce_matches_naive():
     g2 = jax.grad(lambda t: fused_cross_entropy(h, t, labels, chunk=16))(table)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-4,
                                atol=1e-6)
+
+
+def _nine_tap(x, w, stride):
+    """Depthwise 3x3 by definition: pad one pixel on each side, then
+    output pixel i sums inputs stride*i - 1 .. stride*i + 1."""
+    xp = jnp.pad(x, ((0, 0), (1, 1), (1, 1), (0, 0)))
+    ho = (x.shape[1] - 1) // stride + 1
+    wo = (x.shape[2] - 1) // stride + 1
+    out = 0.0
+    for a in range(3):
+        for b in range(3):
+            tap = xp[:, a: a + stride * (ho - 1) + 1: stride,
+                     b: b + stride * (wo - 1) + 1: stride, :]
+            out = out + tap * w[a, b, 0]
+    return out
+
+
+@pytest.mark.parametrize("form", ["grouped", "taps", "platform"])
+@pytest.mark.parametrize("channels", [8, 64])
+@pytest.mark.parametrize("stride", [1, 2])
+def test_depthwise_matches_nine_tap_definition(stride, channels, form):
+    """Each formulation of ``cnn._depthwise`` (and the one this platform
+    compiles) against the definition: values, both gradients, and the
+    stride-2 window pinned by a one-hot input."""
+    depthwise = {"grouped": cnn._depthwise_grouped,
+                 "taps": cnn._depthwise_taps,
+                 "platform": cnn._depthwise}[form]
+    kx, kw, kg = jax.random.split(jax.random.PRNGKey(stride * channels), 3)
+    x = jax.random.normal(kx, (3, 8, 8, channels), jnp.float32)
+    w = jax.random.uniform(kw, (3, 3, 1, channels), jnp.float32, -1.0, 1.0)
+    got = depthwise(x, w, stride)
+    want = _nine_tap(x, w, stride)
+    assert got.shape == want.shape == (3, 8 // stride, 8 // stride, channels)
+
+    def close(a, b):
+        # float32 sums: entries that cancel near zero are held to the
+        # array's own scale
+        b = np.asarray(b)
+        np.testing.assert_allclose(np.asarray(a), b, rtol=1e-5,
+                                   atol=1e-6 * np.abs(b).max())
+
+    close(got, want)
+    g = jax.random.normal(kg, got.shape, jnp.float32)
+    grads = [jax.grad(lambda x, w: jnp.sum(f(x, w, stride) * g),
+                      argnums=(0, 1))(x, w)
+             for f in (depthwise, _nine_tap)]
+    for a, b in zip(*grads):
+        close(a, b)
+    # a one-hot input pins the window: input pixel (3, 3) reaches output
+    # pixels 1 and 2 on each axis (windows 2i-1 .. 2i+1 at stride 2,
+    # i-1 .. i+1 at stride 1), through the mirrored taps
+    hot = jnp.zeros((1, 8, 8, channels)).at[0, 3, 3, :].set(1.0)
+    out = np.asarray(depthwise(hot, w, stride))[0]
+    seen = [i for i in range(out.shape[0]) if 3 - stride * i + 1 in (0, 1, 2)]
+    assert seen == ([1, 2] if stride == 2 else [2, 3, 4])
+    nz = np.argwhere(np.abs(out).sum(-1) > 0)
+    assert sorted(map(tuple, nz)) == [(i, j) for i in seen for j in seen]
+    for i in seen:
+        for j in seen:
+            a, b = 3 - stride * i + 1, 3 - stride * j + 1
+            np.testing.assert_array_equal(out[i, j], np.asarray(w[a, b, 0]))

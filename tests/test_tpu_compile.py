@@ -5,10 +5,14 @@ Covers Algorithm 3's ``fill_aggregate`` at m = 16 uploads of the paper's
 full 12-block CIFAR master and the ``int8:pallas`` codec's
 quantize/dequantize at the same length, and asserts each compiles to a
 Mosaic ``tpu_custom_call`` — i.e. the kernel is compiled, not
-interpreted.  The topology is described inside a module fixture, never at
-import: only one process may load the TPU library at a time.
+interpreted.  Also compiles one inverted-residual block at the fitness
+program's shapes and asserts its depthwise 3x3 is one grouped
+``convolution``, not a pad read through shifted slices.  The topology is
+described inside a module fixture, never at import: only one process may
+load the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,3 +98,31 @@ def test_int8_codec_kernels_compile_for_v5e(one_chip, full_p,
         fn, jax.ShapeDtypeStruct((full_p,), dtype, sharding=one_chip),
         jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip))
     assert "tpu_custom_call" in text
+
+
+def test_depthwise_compiles_to_grouped_convolution(one_chip,
+                                                   no_compile_cache,
+                                                   monkeypatch):
+    """One inverted block (64 -> hidden 256 -> 64) over 500 32x32 images,
+    the fitness program's batch: its depthwise 3x3 is a ``convolution``
+    with one group per hidden channel, and no computation reads a padded
+    activation through several shifted slices (the stencil form, whose
+    taps re-read the activation).  The model picks its depthwise form
+    from the process's platform while tracing; this process is on the
+    CPU, so the test tells it the TPU's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    params = jax.eval_shape(
+        lambda: cnn.branch_init(jax.random.PRNGKey(0), "inverted", 64, 64))
+
+    def spec(s):
+        return jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+
+    text = compiled_text(
+        lambda x, p: cnn.branch_apply("inverted", p, x, 64, 64),
+        spec(jax.ShapeDtypeStruct((500, 32, 32, 64), jnp.float32)),
+        jax.tree.map(spec, params))
+    grouped = [ln for ln in text.splitlines()
+               if " convolution(" in ln and "feature_group_count=256" in ln]
+    assert len(grouped) == 1, grouped
+    for pad in re.findall(r"%(pad[\w.\-]*) = \S+ pad\(", text):
+        assert text.count(f"slice(%{pad})") < 3, pad
