@@ -7,7 +7,7 @@ For each seed, in one process (so the programs compile once): the
 program's first master update and first two evaluations, as a run's
 set-up makes them, compared with the plain reference: the sound
 readings.  For each control seed, three things put in the program's
-place and compared the same way: the control (``reference.control``:
+place and compared the same way: the control (the family's ``control``:
 the reference one precision step below the configuration's, its
 training chain and its scoring of the program's masters), the reference
 with half of every batch left out and the mean taken over the rest, and
@@ -31,8 +31,8 @@ def prefix_run(cell, seed):
     """The program's recorded calls and captured masters up to the end
     of set-up, and the shards those calls touched."""
     from bench import harness
-    clients = harness.build_fleet(seed, cell.traffic, cell.config["search"],
-                                  cell.config["model"]["image"])
+    clients = harness.family(cell).build_fleet(
+        seed, cell.traffic, cell.config["search"], cell.config["model"])
     eng, rec, _ = harness.build_engine(cell, seed, clients, trace=False)
 
     def stop(gen, _report):
@@ -68,14 +68,14 @@ def swapped_answers(errs):
 
 
 def readings(cell, seed: int, control: bool, log=print):
-    from bench import correctness, harness, reference
-    from bench.reference import Reference
+    from bench import correctness, harness
+    fam = harness.family(cell)
     t = time.perf_counter()
     steps, masters, shards = prefix_run(cell, seed)
     prog_errs = [st["errs"] for st in steps if st["kind"] == "eval"]
     t_prog = time.perf_counter() - t
     t = time.perf_counter()
-    ref = Reference(cell.config)
+    ref = fam.Reference(cell.config)
     first = harness.first_update(steps)
     ref_m = ref.run(seed, first, shards)
     ref_e = ref.errors(steps, shards, masters)
@@ -86,14 +86,14 @@ def readings(cell, seed: int, control: bool, log=print):
            "ref_errs": [e.tolist() for e in ref_e]}
     out["sound_detail"] = correctness.worst_leaves(masters, ref_m)
     if control:
-        ctl = reference.control(cell.config)
+        ctl = fam.control(cell.config)
         out["control"] = correctness.compare(
             ctl.run(seed, first, shards), ctl.errors(steps, shards, masters),
             ref_m, ref_e)
         # a training fault: the fitness program scores the master it is
         # given, so its error rates read as the sound ones
-        half = Reference(cell.config, half_batch=True).run(seed, first,
-                                                           shards)
+        half = fam.Reference(cell.config, half_batch=True).run(seed, first,
+                                                               shards)
         out["half_batch"] = correctness.compare(half, ref_e, ref_m, ref_e)
         out["swapped_answers"] = correctness.compare(
             masters, swapped_answers(prog_errs), ref_m, ref_e)

@@ -1,4 +1,5 @@
-"""Plain reference of one real-time NAS search generation.
+"""Plain reference of one real-time NAS search generation over the CNN
+supernet family.
 
 A straightforward restatement, in ``jax.numpy``, of what the program
 computes for the steps the benchmark compares: the supernet of the
@@ -29,8 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.yardstick import BRANCHES
-
+BRANCHES = ("identity", "residual", "inverted", "sepconv")
 HIGHEST = jax.lax.Precision.HIGHEST
 QMAX = 127.0
 

@@ -5,11 +5,14 @@ reading, and the comparison with the plain reference that decides
 The cell is data.  ``BENCHMARK.json`` names its configuration and
 traffic; ``configs/<config>.json``, ``traffic/<traffic>.json``,
 ``limits/<workload>.json`` and ``metrics/<metric>.py`` hold the rest,
-and this module finds each by name.  The system under test is driven
-through its public entry points only: ``make_classification`` /
-``partition_iid`` / ``make_clients`` for the fleet, ``cnn_supernet_api``
-for the supernet, and ``FedEngine.run`` with ``RealTimeNas`` on the
-fused ``vmap`` backend for the search.
+and this module finds each by name.  What knows the model sits in the
+supernet family's module, ``families/<family>.py``, found by the name
+the configuration gives under ``model.family`` (``family``): it makes
+the fleet, builds the program's supernet through its public entry
+points, counts the MACs of one sample, and holds the plain reference.
+This module knows no family: it drives the search through
+``FedEngine.run`` with ``RealTimeNas`` on the configuration's backend,
+pins the window, reads the trace and compares.
 """
 from __future__ import annotations
 
@@ -19,6 +22,7 @@ import importlib.util
 import json
 import os
 import shutil
+import sys
 import tempfile
 import time
 from typing import Callable, Dict, List, Optional
@@ -50,11 +54,23 @@ class Cell:
     traffic: Dict
     limits: Dict[str, float]
     per_layer: List[Dict]
+    root: str = ROOT
 
 
 def _json(path):
     with open(path) as f:
         return json.load(f)
+
+
+def _module(path: str, name: str):
+    """The module at ``path``, loaded once per process under ``name``."""
+    mod = sys.modules.get(name)
+    if mod is None or mod.__file__ != path:
+        spec = importlib.util.spec_from_file_location(name, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[name] = mod
+    return mod
 
 
 def load_cell(workload: str, root: str = ROOT) -> Cell:
@@ -67,23 +83,40 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
     bench = os.path.join(root, "bench")
     per_layer = [m for m in spec["per_layer"]
                  if workload in m.get("workloads", [workload])]
-    return Cell(
+    cell = Cell(
         name=workload, chips=int(wl["chips"]),
-        config=_json(os.path.join(bench, "configs", wl["config"] + ".json")),
+        config=_json(_config_path(root, wl["config"])),
         traffic=_json(os.path.join(bench, "traffic",
                                    wl["traffic"] + ".json")),
         limits=_json(os.path.join(bench, "limits", workload + ".json")),
-        per_layer=per_layer)
+        per_layer=per_layer, root=root)
+    family(cell)
+    return cell
+
+
+def _config_path(root: str, config: str) -> str:
+    return os.path.join(root, "bench", "configs", config + ".json")
+
+
+def family(cell: Cell):
+    """The module of ``families/<family>.py`` under the cell's root, for
+    the family its configuration names; a configuration that names none,
+    or a family with no module, is an error."""
+    name = cell.config["model"].get("family")
+    if not name:
+        raise ValueError(f"{_config_path(cell.root, cell.config['name'])}: "
+                         f"the configuration's model states no family")
+    path = os.path.join(cell.root, "bench", "families", name + ".py")
+    if not os.path.isfile(path):
+        raise FileNotFoundError(f"no module of supernet family {name!r}: "
+                                f"{path}")
+    return _module(path, f"bench_family_{name}")
 
 
 def metric_reader(name: str, root: str = ROOT) -> Callable:
     """``read(record)`` of ``metrics/<name>.py``."""
     path = os.path.join(root, "bench", "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _module(path, f"bench_metric_{name}").read
 
 
 def check_chips(jax, chips: int):
@@ -202,30 +235,6 @@ class WindowClosed(Exception):
     """Raised from the engine's round callback to end the run."""
 
 
-def build_fleet(seed: int, traffic: Dict, search: Dict, image: int):
-    """The fleet's clients from the seed: one synthetic CIFAR-shaped
-    sample set, split IID, each client's shard cut into train and test
-    batches."""
-    from repro.data import make_classification, make_clients, partition_iid
-    if traffic["partition"] != "iid":
-        raise ValueError(f"unknown partition {traffic['partition']!r}")
-    k = traffic["clients"]
-    n = k * traffic["samples_per_client"]
-    x, y = make_classification(seed, n, image=image)
-    clients = make_clients(x, y, partition_iid(seed, n, k),
-                           batch=search["batch"],
-                           test_batch=search["test_batch"], seed=seed)
-    want_tr = traffic["train_per_client"] // search["batch"]
-    want_te = traffic["test_per_client"] // search["test_batch"]
-    for c in clients:
-        if c.train[0].shape[0] != want_tr or c.test[0].shape[0] != want_te:
-            raise ValueError(
-                f"client {c.cid}: {c.train[0].shape[0]} train and "
-                f"{c.test[0].shape[0]} test batches, the traffic file "
-                f"states {want_tr} and {want_te}")
-    return clients
-
-
 def set_precision(jax, cell: Cell):
     """The configuration's matmul precision for every program the process
     compiles from here on (JAX's ``jax_default_matmul_precision``)."""
@@ -235,18 +244,11 @@ def set_precision(jax, cell: Cell):
 
 def build_engine(cell: Cell, seed: int, clients, trace: bool):
     """The search the window times, wrapped in the ``Recorder``."""
-    from repro.configs import get_config
-    from repro.core.supernet import cnn_supernet_api
     from repro.engine import FedEngine, RunConfig
     from repro.obs import TelemetryConfig
     cfg, tr = cell.config, cell.traffic
-    model, search, ex = cfg["model"], cfg["search"], cfg["execution"]
-    api = cnn_supernet_api(get_config(model["program"]).replace(
-        num_layers=len(model["channels"])))
-    if api.master_params() != model["params"]:
-        raise ValueError(f"the program's supernet has "
-                         f"{api.master_params()} parameters, the "
-                         f"configuration states {model['params']}")
+    search, ex = cfg["search"], cfg["execution"]
+    api = family(cell).build_api(cfg["model"])
     if round(tr["participation"] * tr["clients"]) % search["population"]:
         raise ValueError("participants per generation must be a multiple "
                          "of the population")
@@ -273,8 +275,8 @@ def window_population(cell: Cell) -> List[np.ndarray]:
     keys drawn uniformly from the traffic file's fixed seed, the same in
     every run."""
     rng = np.random.default_rng(cell.traffic["window"]["population_seed"])
-    n = len(cell.config["model"]["channels"])
-    return [rng.integers(0, len(cell.config["model"]["branches"]), n
+    model = cell.config["model"]
+    return [rng.integers(0, len(model["branches"]), model["choice_blocks"]
                          ).astype(np.int32)
             for _ in range(cell.config["search"]["population"])]
 
@@ -294,17 +296,17 @@ def window_flops(cell: Cell, calls: List[Dict], clients) -> float:
                     * clients[int(cid)].test[0].shape[1]
                     for cid in c["clients"])
             evals.extend((k, n) for k in c["keys"])
-    return model_flops(train, evals, cell.config["model"],
+    model, sample_macs = cell.config["model"], family(cell).sample_macs
+    return model_flops(train, evals, lambda k: sample_macs(k, model),
                        cell.config["search"]["local_epochs"])
 
 
 def mean_eval_macs(cell: Cell, calls: List[Dict]) -> Optional[float]:
-    from bench.yardstick import subnet_macs
     keys = [k for c in calls if c["kind"] == "eval" for k in c["keys"]]
     if not keys:
         return None
-    return float(np.mean([subnet_macs(k, cell.config["model"])
-                          for k in keys]))
+    model, sample_macs = cell.config["model"], family(cell).sample_macs
+    return float(np.mean([sample_macs(k, model) for k in keys]))
 
 
 def reference_steps(calls: List[Dict]) -> List[Dict]:
@@ -331,14 +333,13 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     import jax
     from jax import monitoring
     from bench import correctness
-    from bench.reference import Reference
     from bench.yardstick import peaks
 
     devices = devices or jax.devices()[:cell.chips]
     counter = CompileCounter(monitoring)
-    model = cell.config["model"]
-    clients = build_fleet(seed, cell.traffic, cell.config["search"],
-                          model["image"])
+    fam = family(cell)
+    clients = fam.build_fleet(seed, cell.traffic, cell.config["search"],
+                              cell.config["model"])
     eng, rec, api = build_engine(cell, seed, clients, trace)
 
     state = {"t_w0": None, "t_end": None, "gens": 0, "mark": 0,
@@ -419,7 +420,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
             c = clients[int(cid)]
             shards[int(cid)] = (c.train[0], c.train[1], c.test[0], c.test[1])
     t_ref = time.perf_counter()
-    ref = Reference(cell.config)
+    ref = fam.Reference(cell.config)
     ref_masters = ref.run(seed, first_update(steps), shards)
     ref_errs = ref.errors(steps, shards, prog_masters)
     prog_errs = [st["errs"] for st in steps if st["kind"] == "eval"]
@@ -449,7 +450,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                   "calls": window_calls}
         metrics = {}
         for m in cell.per_layer:
-            v = metric_reader(m["name"])(record)
+            v = metric_reader(m["name"], cell.root)(record)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
         out["metrics"] = metrics

@@ -1,9 +1,9 @@
 """mfu: model FLOPs of the traced generations over the traced window's
 seconds times the chips times the chip's peak (bf16), in percent.
-Model FLOPs are 2 per MAC for every evaluated (key, test image) and 6
-per MAC for every trained (key, image, epoch), from the benchmark's own
-MAC count (``bench/yardstick.py``); padding and recompute do not
-count."""
+Model FLOPs are 2 per MAC for every evaluated (key, test sample) and 6
+per MAC for every trained (key, sample, epoch), from the supernet
+family's own MAC count (``sample_macs`` of ``bench/families/<family>.py``,
+summed by ``bench/yardstick.py``); padding and recompute do not count."""
 
 
 def read(rec):

@@ -6,7 +6,8 @@ The cell is an entry of ``workloads`` in ``BENCHMARK.json``.  With
 ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` a profiled run reads its per-layer metrics.  Either way
 the run ends by comparing what the timed search produced with the plain
-reference (``bench/reference.py``); the numbers compared, each with its
+reference of the configuration's supernet family
+(``bench/families/``); the numbers compared, each with its
 limit, are the last lines on standard error and the ``checks`` key of
 the result.  The last line of standard output is the result, one JSON
 object.  The run needs a TPU with as many chips as the cell asks for,

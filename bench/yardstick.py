@@ -1,18 +1,16 @@
-"""The benchmark's own arithmetic: model MACs per choice key, the model
-FLOPs of a generation, the bytes Algorithm 3 and the int8 codec need,
-and the table of device peaks.
+"""The benchmark's own arithmetic, whatever the supernet family: the
+model FLOPs of a generation from a family's MACs per sample, the bytes
+Algorithm 3 and the int8 codec need, and the table of device peaks.
 
-Nothing here imports the program: the MAC count restates the supernet
-of ``models/cnn.py`` from the configuration file's channel plan (it is
-pinned against ``repro.core.flops.cnn_subnet_macs`` in the tests), and
-the byte counts follow from the shapes the algorithm touches, whatever
-implements it.
+Nothing here imports the program: the byte counts follow from the
+shapes the algorithm touches, whatever implements it, and the MACs of
+one sample through a choice key's sub-model come from the family
+module (``families/<family>.py``, ``sample_macs``).
 """
 from __future__ import annotations
 
-from typing import Dict, Sequence
+from typing import Callable, Dict, Sequence
 
-BRANCHES = ("identity", "residual", "inverted", "sepconv")
 F32_BYTES = 4
 INT8_BYTES = 1
 
@@ -35,64 +33,21 @@ def peaks(device_kind: str) -> Dict[str, float]:
                          f"known: {sorted(PEAKS)}") from None
 
 
-def _conv_macs(h, w, cin, cout, k, stride=1, groups=1) -> int:
-    return (h // stride) * (w // stride) * cout * (cin // groups) * k * k
-
-
-def branch_macs(name: str, h: int, w: int, cin: int, cout: int) -> int:
-    """MACs of one choice-block branch on an ``h`` x ``w`` input; a block
-    whose channels change is a reduction block (stride 2).  Strided
-    depthwise work is counted at output resolution."""
-    stride = 2 if cout != cin else 1
-    ho, wo = h // stride, w // stride
-    if name == "identity":
-        return 0 if stride == 1 else 2 * _conv_macs(h, w, cin, cout // 2,
-                                                    1, 2)
-    if name == "residual":
-        return (_conv_macs(h, w, cin, cout, 3, stride)
-                + _conv_macs(ho, wo, cout, cout, 3))
-    if name == "inverted":
-        hid = 4 * cin
-        return (_conv_macs(h, w, cin, hid, 1)
-                + _conv_macs(h, w, hid, hid, 3, stride, groups=hid)
-                + _conv_macs(ho, wo, hid, cout, 1))
-    if name == "sepconv":
-        return (_conv_macs(h, w, cin, cin, 3, stride, groups=cin)
-                + _conv_macs(ho, wo, cin, cout, 1)
-                + _conv_macs(ho, wo, cout, cout, 3, groups=cout)
-                + _conv_macs(ho, wo, cout, cout, 1))
-    raise ValueError(f"unknown branch {name!r}")
-
-
-def subnet_macs(key: Sequence[int], model: Dict) -> int:
-    """Forward MACs of one image through the subnet ``key`` selects, for
-    the configuration file's ``model`` section (``channels``,
-    ``stem_channels``, ``image``, ``classes``)."""
-    chans = model["channels"]
-    cin = model["stem_channels"]
-    h = w = model["image"]
-    total = _conv_macs(h, w, 3, cin, 3)
-    for i, cout in enumerate(chans):
-        total += branch_macs(BRANCHES[int(key[i])], h, w, cin, cout)
-        if cout != cin:
-            h, w = h // 2, w // 2
-        cin = cout
-    return int(total + cin * model["classes"])
-
-
-def model_flops(train: Sequence, evals: Sequence, model: Dict,
+def model_flops(train: Sequence, evals: Sequence,
+                sample_macs: Callable[[Sequence[int]], int],
                 epochs: int) -> float:
-    """Model FLOPs of the work listed: ``train`` holds (key, images) for
-    every trained (key, client) pair, ``evals`` (key, images) for every
-    evaluated (key, client set).  2 FLOPs per MAC forward, 6 per MAC
-    for a training step (forward and backward), per epoch; padding rows
-    and recompute are not counted."""
+    """Model FLOPs of the work listed: ``train`` holds (key, samples) for
+    every trained (key, client) pair, ``evals`` (key, samples) for every
+    evaluated (key, client set), and ``sample_macs(key)`` gives the
+    forward MACs of one sample through the sub-model ``key`` selects.
+    2 FLOPs per MAC forward, 6 per MAC for a training step (forward and
+    backward), per epoch; padding rows and recompute are not counted."""
     cache: Dict[tuple, int] = {}
 
     def macs(key):
         k = tuple(int(b) for b in key)
         if k not in cache:
-            cache[k] = subnet_macs(k, model)
+            cache[k] = sample_macs(k)
         return cache[k]
 
     return (sum(6.0 * macs(k) * n * epochs for k, n in train)

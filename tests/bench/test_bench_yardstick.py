@@ -1,6 +1,6 @@
-"""The benchmark's own arithmetic: its copy of the supernet's MAC count,
-the model FLOPs of a generation, the bytes Algorithm 3 and the int8
-codec need, and the table of peaks."""
+"""The benchmark's own arithmetic: the CNN family's copy of the
+supernet's MAC count, the model FLOPs of a generation, the bytes
+Algorithm 3 and the int8 codec need, and the table of peaks."""
 import json
 import os
 import sys
@@ -13,6 +13,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 sys.path.insert(0, ROOT)
 
 from bench import yardstick  # noqa: E402
+from bench.families import cnn  # noqa: E402
 
 
 def _model(name):
@@ -30,14 +31,14 @@ def test_macs_match_program(config):
     keys = [rng.integers(0, 4, len(model["channels"])) for _ in range(200)]
     keys += [np.full(len(model["channels"]), b) for b in range(4)]
     for k in keys:
-        assert yardstick.subnet_macs(k, model) == cnn_subnet_macs(
+        assert cnn.subnet_macs(k, model) == cnn_subnet_macs(
             k, len(model["channels"]))
 
 
 def test_macs_of_smallest_and_largest_subnets():
     model = _model("cifar12")
-    ident = yardstick.subnet_macs([0] * 12, model)
-    resid = yardstick.subnet_macs([1] * 12, model)
+    ident = cnn.subnet_macs([0] * 12, model)
+    resid = cnn.subnet_macs([1] * 12, model)
     # stem, the three reduction identities (two 1x1 stride-2 convs) and
     # the head
     stem = 32 * 32 * 64 * 3 * 9
@@ -50,9 +51,10 @@ def test_macs_of_smallest_and_largest_subnets():
 def test_model_flops_counts_forward_and_training():
     model = _model("cifar12")
     k = [0] * 12
-    m = yardstick.subnet_macs(k, model)
+    m = cnn.subnet_macs(k, model)
     flops = yardstick.model_flops([(k, 500)], [(k, 1000), (k, 1000)],
-                                  model, epochs=2)
+                                  lambda key: cnn.sample_macs(key, model),
+                                  epochs=2)
     assert flops == 6.0 * m * 500 * 2 + 2.0 * m * 2000
 
 
